@@ -35,7 +35,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
-import jax.core as jcore
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 

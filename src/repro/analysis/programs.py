@@ -226,9 +226,10 @@ def _build_regime_b(sampled: bool) -> ProgramInstance:
 
     from repro.configs import SHAPES, get_reduced
     from repro.launch import steps
+    from repro.launch.mesh import make_mesh
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
     arch = "qwen2-0.5b"
     cfg = get_reduced(arch)
     shape = dc.replace(SHAPES["train_4k"], seq_len=16, global_batch=n_dev)
@@ -266,7 +267,7 @@ def _build_regime_b(sampled: bool) -> ProgramInstance:
             b = jax.tree.map(zeros, structs[2])
             return jax.device_put((sched.at(t), b), tuple(ins[1:]))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         round_args = tuple(rest(t) for t in range(N_ROUNDS))
     return ProgramInstance(
         name="regimeB.sampled" if sampled else "regimeB.resident",
@@ -275,7 +276,7 @@ def _build_regime_b(sampled: bool) -> ProgramInstance:
         fresh_state=lambda: _copy_state(state0),
         donate=donate, m=m,
         jit_kwargs=dict(in_shardings=ins, out_shardings=outs),
-        ctx=lambda: mesh)
+        ctx=lambda: jax.set_mesh(mesh))
 
 
 def build_regime_b_resident() -> ProgramInstance:

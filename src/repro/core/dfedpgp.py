@@ -37,6 +37,15 @@ def _check_uniform_dtype(layout) -> None:
             f"dtype shared parts must use the tree-form round_fn")
 
 
+def _unit_gates(batches_u):
+    """(m, K_u) all-ones step gates, derived from the batch so that they
+    carry its client-axis sharding (vmap refuses mapped inputs sharded
+    differently)."""
+    leaf = jax.tree.leaves(batches_u)[0]
+    lead = leaf[(slice(None), slice(None)) + (0,) * (leaf.ndim - 2)]
+    return jnp.ones_like(lead, jnp.float32)
+
+
 class DFedPGPState(NamedTuple):
     params: Any            # stacked (m, ...) — biased u leaves + personal v leaves
     mu: jnp.ndarray        # (m,)
@@ -227,8 +236,7 @@ class DFedPGP:
                              "has no buffer to gauge")
         lr_scale = self.lr_decay ** state.round.astype(jnp.float32)
         if step_gate_u is None:
-            shp = jax.tree.leaves(batches["u"])[0].shape[:2]   # (m, K_u)
-            step_gate_u = jnp.ones(shp, jnp.float32)
+            step_gate_u = _unit_gates(batches["u"])
 
         params, opt_u, opt_v, (loss_v, loss_u) = jax.vmap(
             self.local_update, in_axes=(0, 0, 0, 0, 0, 0, None, 0))(
@@ -517,8 +525,7 @@ class DFedPGP:
                              "round_fn")
         lr_scale = self.lr_decay ** state.round.astype(jnp.float32)
         if step_gate_u is None:
-            shp = jax.tree.leaves(batches["u"])[0].shape[:2]   # (m, K_u)
-            step_gate_u = jnp.ones(shp, jnp.float32)
+            step_gate_u = _unit_gates(batches["u"])
 
         def client(flat_row, personal, mu_i, opt_u, opt_v, bv, bu, gate):
             return self.local_update_flat(
@@ -604,8 +611,7 @@ class DFedPGP:
         lr_scale = self.lr_decay ** state.round.astype(jnp.float32)
         active = jnp.asarray(active, jnp.int32)
         if step_gate_u is None:
-            shp = jax.tree.leaves(batches["u"])[0].shape[:2]  # (n_act, K_u)
-            step_gate_u = jnp.ones(shp, jnp.float32)
+            step_gate_u = _unit_gates(batches["u"])
 
         take = lambda a: jnp.take(a, active, axis=0)
         with jax.named_scope("dfedpgp.gather"):

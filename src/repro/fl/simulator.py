@@ -279,9 +279,12 @@ def run_experiment(algo_name: str, sim: SimConfig,
     """Returns history dict with per-eval round accuracies.  With
     return_params, history["params"] carries the final stacked
     personalized models (regression tests compare them across engine
-    knobs).  sink: optional obs.MetricsSink — every round then emits one
-    schema-v1 "round" record (docs/observability.md) carrying the round
-    metrics, the wire meter, and (spec.telemetry) the in-graph gauges;
+    knobs), history["state"] the final train state, history["layout"]
+    its FlatLayout (None for tree-form engines) and, on the sync runtime,
+    history["round_lowered"] the round program as jax.stages.Lowered.
+    sink: optional obs.MetricsSink — every round then emits one schema-v1
+    "round" record (docs/observability.md) carrying the round metrics, the
+    wire meter, and (spec.telemetry) the in-graph gauges;
     fetching gauges to the host costs one device sync per round, which is
     why emission is opt-in while `history` stays the cheap default."""
     model_cfg = model_cfg or cnn.CNNConfig(image_size=sim.image_size,
@@ -365,7 +368,7 @@ def run_experiment(algo_name: str, sim: SimConfig,
         state, layout = algo.init_flat(stacked)
         eval_params = lambda s: algo.eval_params_flat(s, layout)
     else:
-        state = algo.init(stacked)
+        state, layout = algo.init(stacked), None
         eval_params = algo.eval_params
 
     @jax.jit
@@ -494,6 +497,14 @@ def run_experiment(algo_name: str, sim: SimConfig,
     history["final_acc"] = history["acc"][-1] if history["acc"] else float("nan")
     if return_params:
         history["params"] = eval_params(state)
+        history["state"], history["layout"] = state, layout
+        if sim.rounds:
+            # the round program that ran, lowered at the last round's
+            # arguments (the final state has the input state's types)
+            history["round_lowered"] = (
+                round_sampled_jit.lower(state, P_act, active, batches, gate_u)
+                if active is not None
+                else round_jit.lower(state, ctx, batches, gate_u))
     return history
 
 

@@ -21,7 +21,7 @@ without materializing the (B, d, n) gathered weight tensor the naive
   the output is always f32 — the same contract as the jnp oracle.
 
 `interpret=True` runs the same kernel body (including the DMAs) on CPU —
-how the kernel is validated in this container; interpret mode executes
+how the kernel is validated without a TPU; interpret mode executes
 grid steps sequentially in Python, so it is a correctness path, not a CPU
 fast path (the serve engine's auto dispatch uses the oracle off-TPU).
 """
@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .gossip_gather import row_view
+
 BB = 8              # requests per output panel; slab DMAs in flight
 BN = 128            # output-class panel width (one lane tile)
 
@@ -39,8 +41,8 @@ BN = 128            # output-class panel width (one lane tile)
 def _head_kernel(uid_ref, w_ref, b_ref, h_ref, out_ref, wscr, bscr,
                  wsems, bsems):
     # uid_ref: (Bp,) scalar-prefetch (SMEM).  w_ref: the WHOLE (m, d, n)
-    # classifier block in HBM/ANY; b_ref: the WHOLE (m, n) bias block —
-    # the kernel gathers each request's slab itself, every copy started
+    # classifier block in HBM/ANY; b_ref: the WHOLE (m, 1, n) bias row view
+    # — the kernel gathers each request's slab itself, every copy started
     # before the first wait.
     i = pl.program_id(0)
     nt = pl.program_id(1)
@@ -53,7 +55,7 @@ def _head_kernel(uid_ref, w_ref, b_ref, h_ref, out_ref, wscr, bscr,
 
     def bcopy(r):
         return pltpu.make_async_copy(
-            b_ref.at[uid_ref[i * bb + r], pl.ds(nt * bn, bn)],
+            b_ref.at[uid_ref[i * bb + r], :, pl.ds(nt * bn, bn)],
             bscr.at[r], bsems.at[r])
 
     for r in range(bb):
@@ -64,11 +66,11 @@ def _head_kernel(uid_ref, w_ref, b_ref, h_ref, out_ref, wscr, bscr,
         bcopy(r).wait()
 
     h = h_ref[...].astype(jnp.float32)                       # (bb, d)
-    acc = jnp.stack([
-        jnp.dot(h[r], wscr[r].astype(jnp.float32),
+    acc = jnp.concatenate([
+        jnp.dot(h[r:r + 1], wscr[r].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
-        for r in range(bb)])                                 # (bb, bn)
-    out_ref[...] = acc + bscr[...].astype(jnp.float32)
+        for r in range(bb)], axis=0)                         # (bb, bn)
+    out_ref[...] = acc + bscr[...].reshape(bb, bn).astype(jnp.float32)
 
 
 def head_gather_matmul_pallas(uid: jnp.ndarray, H: jnp.ndarray,
@@ -80,11 +82,14 @@ def head_gather_matmul_pallas(uid: jnp.ndarray, H: jnp.ndarray,
 
     uid: (B,) int32 request->user ids; H: (B, d) trunk features (any float
     dtype); W: (m, d, n) stacked personal classifiers; b: (m, n) stacked
-    biases.  W and b are never copied whole: they stay in HBM and each
-    request's (d, block_n) slab is gathered by DMA.  Host-side padding:
-    uid/H to the block_b panel (user 0, zero rows — sliced off), n to the
-    block_n lane panel (zero classes), d to the f32 sublane tile when
-    misaligned (zero features contribute nothing to the dot).
+    biases.  W and b stay in HBM and each request's (d, block_n) slab is
+    gathered by DMA.  Host-side padding: uid/H to the block_b panel (user 0,
+    zero rows — sliced off); n to the block_n lane panel (zero classes:
+    Mosaic slices lanes only in whole 128-lane tiles, so a head narrower
+    than that is padded, and copied, on every call); d to the f32
+    sublane tile when misaligned (zero features contribute nothing to the
+    dot).  b reaches the kernel as an (m, 1, n) row view (see
+    gossip_gather: a single-row DMA needs the row on a leading axis).
     """
     B, d = H.shape
     m, dw, n = W.shape
@@ -119,14 +124,15 @@ def head_gather_matmul_pallas(uid: jnp.ndarray, H: jnp.ndarray,
         out_specs=pl.BlockSpec((block_b, block_n),
                                lambda i, nt, uid_ref: (i, nt)),
         scratch_shapes=[pltpu.VMEM((block_b, dp, block_n), W.dtype),
-                        pltpu.VMEM((block_b, block_n), b.dtype),
+                        pltpu.VMEM((block_b, 1, block_n), b.dtype),
                         pltpu.SemaphoreType.DMA((block_b,)),
                         pltpu.SemaphoreType.DMA((block_b,))],
     )
     out = pl.pallas_call(
         _head_kernel,
         grid_spec=grid_spec,
+        name="head_gather",
         out_shape=jax.ShapeDtypeStruct((Bp, np_), jnp.float32),
         interpret=interpret,
-    )(uid.astype(jnp.int32), W, b, H)
+    )(uid.astype(jnp.int32), W, row_view(b), H)
     return out[:B, :n]

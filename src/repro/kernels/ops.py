@@ -4,7 +4,7 @@ Each op picks the execution path:
   - TPU: the Pallas kernel (compiled);
   - CPU/tests: either the pure-jnp oracle (fast) or the kernel in
     interpret mode (`interpret=True` runs the kernel body in Python —
-    how the kernels are validated in this container).
+    how the kernels are validated without a TPU).
 
 Loud-knob rule (docs/ci.md, tests/test_kernels.py): every knob that only
 parameterizes the Pallas kernel — DMA panel heights, block widths, the

@@ -131,7 +131,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, gossip: str = "matrix",
     t0 = time.time()
     fn, ins, outs, args, donate = steps.build_step(cfg, mesh, layout, shape,
                                                    **kw)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=ins, out_shardings=outs,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)

@@ -9,17 +9,25 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """jax.make_mesh with Auto axes: the sharding rules of this package
+    are written for Auto (propagated) sharding, while jax.make_mesh
+    defaults to Explicit axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_clients: int = 4, model: int = 2):
     """Small mesh over forced host devices for tests / examples."""
-    return jax.make_mesh((n_clients, model), ("data", "model"))
+    return make_mesh((n_clients, model), ("data", "model"))
 
 
 def client_layout(mesh, strategy: str = "auto", arch_id: str = ""):

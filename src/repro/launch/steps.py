@@ -37,11 +37,6 @@ from repro.models.config import ModelConfig
 from repro.optim import SGD, SGDState
 from . import sharding
 
-try:                                     # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                   # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 class Layout(NamedTuple):
     client_axes: Tuple[str, ...]   # stacked-client dim of every leaf
@@ -326,7 +321,7 @@ def make_ppermute_mix(mesh: Mesh, layout: Layout, mask, params_struct,
                                              offsets)) * 0.5
             return u2, mu2
 
-        u2, mu2 = _shard_map(
+        u2, mu2 = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), u_specs, P(axis)),
             out_specs=(u_specs, P(axis)))(rnd, u, mu)
@@ -364,7 +359,7 @@ def make_ppermute_mix_flat(mesh: Mesh, layout: Layout, d_flat: int,
                                            offsets)) * 0.5
             return flat2, mu2
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), buf_spec, P(axis)),
             out_specs=(buf_spec, P(axis)))(rnd, flat, mu)

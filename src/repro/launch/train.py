@@ -25,11 +25,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.configs import get_config, get_reduced
 from repro.core import partition, topology
 from repro.launch import steps
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.obs import gauges as obs_gauges
@@ -90,6 +92,9 @@ def main(argv=None):
                          "resident lifecycle)")
     ap.add_argument("--reduced", action="store_true",
                     help="use the reduced (smoke) variant of the arch")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model to this many layers (depth only; "
+                         "every width stays the config's); 0 keeps it")
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--sample", type=float, default=1.0,
                     help="participation fraction per round (docs/scale.md): "
@@ -114,22 +119,29 @@ def main(argv=None):
                          "jax.profiler.trace (view phase-labelled device "
                          "timelines in xprof/tensorboard)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
+    if args.layers < 0:
+        ap.error(f"--layers {args.layers}: want >= 0")
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
+    if args.layers:
+        cfg = cfg.replace(n_layers=args.layers)
     m = args.clients
     n_dev = jax.device_count()
-    if m * args.tp > n_dev:
-        print(f"[train] note: {m}x{args.tp} logical > {n_dev} devices; "
-              f"running unsharded on {n_dev} device(s)")
-        mesh = None
-    else:
-        mesh = make_host_mesh(m, args.tp)
-
     gossip = args.gossip
-    if gossip == "ppermute" and mesh is None:
-        print("[train] note: ppermute needs the client mesh; "
-              "falling back to matrix gossip")
-        gossip = "matrix"
+    if m * args.tp == n_dev:
+        mesh = make_host_mesh(m, args.tp)
+    elif n_dev == 1:
+        if gossip == "ppermute":
+            ap.error("--gossip ppermute permutes client shards across "
+                     "devices; one device holds them all — use --gossip "
+                     "matrix")
+        mesh = None
+        print(f"[train] unsharded: {m} clients x tp {args.tp} on the one "
+              f"{jax.devices()[0].platform} device")
+    else:
+        ap.error(f"--clients {m} x --tp {args.tp} = {m * args.tp} mesh "
+                 f"ranks; {n_dev} devices present (the mesh takes them all)")
     if not 0.0 < args.sample <= 1.0:
         ap.error(f"--sample {args.sample}: want a fraction in (0, 1]")
     sampled = args.sample < 1.0
@@ -155,33 +167,45 @@ def main(argv=None):
 
     api = get_model(cfg)
     layout = steps.Layout(("data",), (), ("model",), (), m, args.batch)
-    algo, mask, _, flat_layout = steps.build_train_algo(
+    algo, mask, params_struct, flat_layout = steps.build_train_algo(
         cfg, mesh, layout, k_u=args.k_u, k_v=args.k_v, spec=spec, lr=0.02)
+    template = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), params_struct)
+
+    def init_state(keys):
+        stacked = jax.vmap(lambda k: api.init_params(k, cfg))(keys)
+        if flat_layout is not None:
+            return algo.init_flat(stacked, flat_layout)[0]
+        return algo.init(stacked)
 
     key = jax.random.PRNGKey(0)
-    stacked = jax.vmap(lambda k: api.init_params(k, cfg))(
-        jax.random.split(key, m))
-    template = jax.tree.map(lambda x: x[0], stacked)
-    if sampled:
-        state, flat_layout = algo.init_flat(stacked, flat_layout)
+    keys = jax.random.split(key, m)
+    jit_kw: dict = dict(donate_argnums=(0,))
+    if mesh is None:
+        state = jax.jit(init_state)(keys)
+    else:
+        # each client's state is built on, and stays on, its own mesh rank
+        struct = jax.eval_shape(init_state, keys)
+        st_sh = (steps.flat_state_shardings if flat_layout is not None
+                 else steps.state_shardings)(struct, mesh, layout)
+        state = jax.jit(init_state, out_shardings=st_sh)(keys)
+        jit_kw["out_shardings"] = (st_sh,
+                                   NamedSharding(mesh, PartitionSpec()))
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
+    if sampled:
+        @functools.partial(jax.jit, **jit_kw)
         def round_fn(state, P_act, active, batches):
             # compact working set: gather active rows, round, scatter back
             return algo.round_fn_sampled(state, P_act, active, batches,
                                          flat_layout)
     elif args.resident:
-        state, flat_layout = algo.init_flat(stacked, flat_layout)
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
+        @functools.partial(jax.jit, **jit_kw)
         def round_fn(state, P, batches):
             # the FLAT BUFFER is the donated carry — the round updates the
             # (m, d_flat) buffer in place, no tree materializes
             return algo.round_fn_flat(state, P, batches, flat_layout)
     else:
-        state = algo.init(stacked)
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
+        @functools.partial(jax.jit, **jit_kw)
         def round_fn(state, P, batches):
             return algo.round_fn(state, P, batches)
 
@@ -203,7 +227,8 @@ def main(argv=None):
     timer = obs.PhaseTimer()
 
     import contextlib
-    ctx = mesh if mesh is not None else contextlib.nullcontext()
+    ctx = (jax.set_mesh(mesh) if mesh is not None
+           else contextlib.nullcontext())
     with ctx, obs.maybe_trace(args.profile or None):
         for r in range(args.rounds):
             with timer.phase("data"):
@@ -217,6 +242,9 @@ def main(argv=None):
                                         (n_lead, args.k_u, args.batch),
                                         args.seq),
                 }
+                if mesh is not None and sampler is None:
+                    batches = jax.device_put(batches, steps.batch_specs(
+                        batches, mesh, layout, n_lead=2))
             active = None
             with timer.phase("round", block=True) as ph:
                 if sampler is not None:

@@ -63,7 +63,7 @@ def backbone(params, x, positions, cfg: ModelConfig):
 
 def forward_train(params, tokens, cfg: ModelConfig, positions=None,
                   last_only: bool = False):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     if positions is None:
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
     x = backbone(params, x, positions, cfg)
@@ -106,7 +106,7 @@ def _quantize(x):
 def _decode_step_quant(params, cache, tokens, pos, cfg: ModelConfig):
     """int8-KV decode: dequantization fuses into the attention matmul, so
     HBM traffic per step is the int8 cache + scales, not a bf16 cache."""
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     B = tokens.shape[0]
 
     def body(h, lc):
@@ -151,7 +151,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
     """tokens: (B, 1); pos: scalar int32 (current absolute position)."""
     if cfg.kv_quant:
         return _decode_step_quant(params, cache, tokens, pos, cfg)
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
 
     def body(h, lp_and_cache):
         lp, ck, cv = lp_and_cache

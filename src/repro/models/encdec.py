@@ -119,7 +119,7 @@ def forward_train(params, batch, cfg: ModelConfig, last_only: bool = False):
     """batch: {frames (B,F,D), tokens (B,S), labels (B,S)} -> logits."""
     enc_out = encode(params, batch["frames"], cfg)
     tokens = batch["tokens"]
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     x = x + L.sinusoid_positions(tokens.shape[1], cfg.d_model).astype(x.dtype)[None]
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
 
@@ -166,7 +166,7 @@ def prefill_cross(params, frames, cfg: ModelConfig, cache):
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     # sinusoidal position embedding evaluated at the current position
     div = jnp.exp(jnp.arange(0, cfg.d_model, 2, dtype=jnp.float32)
                   * (-jnp.log(10000.0) / cfg.d_model))

@@ -172,7 +172,7 @@ def _attn_layer_fwd(lp, x, positions, cfg):
 
 def forward_train(params, tokens, cfg: ModelConfig, positions=None,
                   last_only: bool = False):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     if positions is None:
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
     P, tail = _layout(cfg)
@@ -227,7 +227,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     P, tail = _layout(cfg)
     new_cache = dict(cache)
 

@@ -29,6 +29,34 @@ def embed_init(key, shape, dtype):
     return (jax.random.normal(key, shape) * 0.02).astype(dtype)
 
 
+@jax.custom_vjp
+def _lookup(table, tokens):
+    return table[tokens]
+
+
+def _lookup_fwd(table, tokens):
+    return table[tokens], (table, tokens)
+
+
+def _lookup_bwd(res, g):
+    table, tokens = res
+    grad = jnp.zeros_like(table).at[tokens].add(g)
+    # the table gradient is summed over the batch's tokens from zero, and
+    # only then meets whatever the optimizer adds (weight decay): without
+    # the barrier XLA may fold that add into the scatter's base in one
+    # program and not in another, which reorders the f32 sum for repeated
+    # tokens, so the same step would round differently by program form
+    return jax.lax.optimization_barrier(grad), None
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
+
+
+def embed_tokens(table, tokens, dtype):
+    """Rows of the (vocab, d) table for integer `tokens`, cast to `dtype`."""
+    return _lookup(table.astype(dtype), tokens)
+
+
 # ---------------------------------------------------------------------------
 # norms
 # ---------------------------------------------------------------------------

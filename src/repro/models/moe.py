@@ -76,16 +76,17 @@ def _constrain_dispatch(buf, cfg: ModelConfig):
     """Pin the (E, C, D) dispatch buffer to (expert_axis, token_axis, -):
     without it GSPMD replicates the scatter output per data shard and
     all-reduces ~10 GB per MoE layer (§Perf P2 iteration 3).  No-op when no
-    mesh context / axes are absent (FL sim, vmapped client stacks)."""
+    mesh is set (jax.set_mesh) or it lacks the axes (FL sim, vmapped client
+    stacks); any other failure of the constraint raises."""
     if not cfg.moe_dispatch_axes:
         return buf
-    try:
-        from jax.sharding import PartitionSpec as P
-        ea, ta = cfg.moe_dispatch_axes
-        return jax.lax.with_sharding_constraint(buf, P(ea or None,
-                                                       ta or None, None))
-    except Exception:
+    from jax.sharding import PartitionSpec as P
+    ea, ta = cfg.moe_dispatch_axes
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not {a for a in (ea, ta) if a} <= set(mesh.axis_names):
         return buf
+    return jax.lax.with_sharding_constraint(buf, P(ea or None, ta or None,
+                                                   None))
 
 
 def _moe_ffn_dispatch(p, x, cfg: ModelConfig):
@@ -293,7 +294,7 @@ def _moe_block(lp, x, positions, cfg: ModelConfig):
 
 def forward_train(params, tokens, cfg: ModelConfig, positions=None,
                   last_only: bool = False):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     if positions is None:
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
 
@@ -364,7 +365,7 @@ def _attn_decode(lp, h, pos, cc, cfg):
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     keys = ("c", "r") if cfg.kv_lora else ("k", "v")
 
     def dense_body(h, lc):

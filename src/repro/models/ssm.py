@@ -269,7 +269,7 @@ def init_params(key, cfg: ModelConfig):
 
 def forward_train(params, tokens, cfg: ModelConfig, positions=None,
                   last_only: bool = False):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     for i, lp in enumerate(params["layers"]):
         fn = mlstm_block if _kind(i, cfg) == "mlstm" else slstm_block
         if cfg.remat:
@@ -309,7 +309,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int):
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
     del pos
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     new_cache = []
     for i, (lp, st) in enumerate(zip(params["layers"], cache)):
         if _kind(i, cfg) == "mlstm":

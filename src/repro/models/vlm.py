@@ -87,7 +87,7 @@ init_cache = dense.init_cache
 
 
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig):
-    x = params["embed"].astype(cfg.cdtype)[tokens]
+    x = L.embed_tokens(params["embed"], tokens, cfg.cdtype)
     B = tokens.shape[0]
     posv3 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (3, B, 1))
 

@@ -182,16 +182,14 @@ def edge_count(P) -> int:
 # device-memory meters (moved here from benchmarks/common.py — obs owns
 # resource gauges now; benchmarks re-export for compat)
 # ---------------------------------------------------------------------------
-def peak_device_memory():
-    """Peak bytes in use on device 0, from the backend's allocator stats
-    (jax Device.memory_stats — populated on TPU/GPU).  The CPU backend
-    reports no allocator stats, so callers pair this with the
-    deterministic `accounted_bytes` meter and record None here — the
-    committed artifact then documents which meter produced the number."""
-    try:
-        stats = jax.devices()[0].memory_stats()
-    except Exception:
-        return None
+def peak_device_memory(device=None):
+    """Peak bytes in use on `device` (default: device 0), from the
+    backend's allocator stats (jax Device.memory_stats — populated on
+    TPU/GPU).  The CPU backend reports no allocator stats, so callers pair
+    this with the deterministic `accounted_bytes` meter and record None
+    here — the committed artifact then documents which meter produced the
+    number.  A backend that fails to report raises."""
+    stats = (device or jax.devices()[0]).memory_stats()
     if not stats:
         return None
     peak = stats.get("peak_bytes_in_use")

@@ -14,6 +14,7 @@ from repro.checkpoint import load_pytree, save_pytree
 from repro.core import dfedpgp, topology
 from repro.hetero import profiles
 from repro.hetero.runtime import AsyncRuntime
+from repro.launch.mesh import make_mesh
 from repro.optim import SGD
 
 
@@ -94,7 +95,7 @@ def test_sharded_flat_state_checkpoint_roundtrip(tmp_path):
     b = _batches(cu, cv, 1, 2)
     state, _ = algo.round_fn_flat(state, sched.at(0), b, layout)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     lay = steps.Layout(("data",), (), ("model",), (), m, 1)
     shardings = steps.flat_state_shardings(state, mesh, lay)
     sharded = jax.device_put(state, shardings)

@@ -96,6 +96,37 @@ def test_gossip_gather_ops_dispatch():
         np.asarray(ref.gossip_gather_ref(idx, w, U)), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kernel", ["gossip_gather", "gossip_scatter",
+                                    "topk_gather"])
+def test_smem_row_chunks_match_oracle(kernel, monkeypatch):
+    """A neighbor or row table larger than one call's SMEM budget is split
+    into row chunks; the chunked result equals the oracle."""
+    from repro.kernels import gossip_gather, gossip_scatter, topk_gather
+    m, k, d = 40, 3, 130
+    idx, w, U = _sparse_mix_inputs(m, k, d, jnp.float32)
+    # budgets of 16 rows: three chunks, the last one short
+    monkeypatch.setattr(gossip_gather, "SMEM_ENTRIES", 16 * k)
+    monkeypatch.setattr(gossip_scatter, "SMEM_ROWS", 16)
+    if kernel == "gossip_gather":
+        got = gossip_gather.gossip_gather_pallas(idx, w, U, interpret=True)
+        want = ref.gossip_gather_ref(idx, w, U)
+    elif kernel == "topk_gather":
+        cols = jnp.tile(jnp.arange(0, d, 13, dtype=jnp.int32), (m, 1))
+        vals = U[:, :cols.shape[1]]
+        got = topk_gather.topk_gather_pallas(idx, w, vals, cols, d,
+                                             interpret=True)
+        want = ref.topk_gather_ref(idx, w, vals, cols, d)
+    else:
+        rows = jnp.arange(0, m, 1, dtype=jnp.int32)[::-1][:37]
+        X = U[:37] * 2.0
+        got = gossip_scatter.gossip_scatter_pallas(rows, X, U,
+                                                   accumulate=True,
+                                                   interpret=True)
+        want = ref.gossip_scatter_ref(rows, X, U, accumulate=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------

@@ -50,9 +50,10 @@ _SUBPROCESS = textwrap.dedent("""
     import numpy as np
     from repro.core import gossip, partition, topology
     from repro.launch import steps
+    from repro.launch.mesh import make_mesh
 
     m = 8
-    mesh = jax.make_mesh((m, 1), ("data", "model"))
+    mesh = make_mesh((m, 1), ("data", "model"))
     layout = steps.Layout(("data",), (), ("model",), (), m, 1)
     key = jax.random.PRNGKey(0)
     params = {"body": jax.random.normal(key, (m, 6, 4)),
@@ -128,9 +129,10 @@ _SUBPROCESS_RESIDENT = textwrap.dedent("""
     from repro.configs import SHAPES, get_reduced
     from repro.core import topology
     from repro.launch import steps
+    from repro.launch.mesh import make_mesh
 
     m = 8
-    mesh = jax.make_mesh((m, 1), ("data", "model"))
+    mesh = make_mesh((m, 1), ("data", "model"))
     cfg = get_reduced("qwen2-0.5b")
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=16,
                                 global_batch=m)

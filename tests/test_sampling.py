@@ -27,6 +27,7 @@ from repro.hetero import profiles
 from repro.hetero.runtime import AsyncRuntime
 from repro.kernels import ops, ref
 from repro.launch import steps
+from repro.launch.mesh import make_mesh
 from repro.optim import SGD
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -285,7 +286,8 @@ def test_mass_split_components():
 # ---------------------------------------------------------------------------
 # gossip_scatter kernel: interpret parity at awkward shapes
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("m,d", [(5, 3), (13, 130), (7, 257), (32, 64)])
+@pytest.mark.parametrize("m,d", [(5, 3), (13, 130), (7, 257), (32, 64),
+                                 (40, 300), (21, 384)])
 @pytest.mark.parametrize("accumulate", [False, True])
 def test_gossip_scatter_interpret_parity(m, d, accumulate):
     """The pallas write-back (interpret mode on CPU) is bit-identical to
@@ -303,16 +305,21 @@ def test_gossip_scatter_interpret_parity(m, d, accumulate):
 
 
 def test_gossip_scatter_bf16_buffer_parity():
-    U = jax.random.normal(jax.random.PRNGKey(0), (9, 70)).astype(
-        jnp.bfloat16)
-    rows = jnp.asarray([0, 4, 8], jnp.int32)
-    X = jax.random.normal(jax.random.PRNGKey(1), (3, 70))
-    for acc in (False, True):
-        got = ops.gossip_scatter(rows, X, U, accumulate=acc, force="pallas")
-        want = ref.gossip_scatter_ref(rows, X, U, accumulate=acc)
-        assert got.dtype == jnp.bfloat16
-        np.testing.assert_array_equal(
-            np.asarray(got, np.float32), np.asarray(want, np.float32))
+    # (9, 70) lies wholly in partial tiles; (35, 300) has whole 16-row bf16
+    # tiles for the kernel and partial edge tiles in both dims
+    for (m, d), rows in (((9, 70), [0, 4, 8]),
+                         ((35, 300), [0, 5, 17, 31, 32, 34])):
+        U = jax.random.normal(jax.random.PRNGKey(0), (m, d)).astype(
+            jnp.bfloat16)
+        rows = jnp.asarray(rows, jnp.int32)
+        X = jax.random.normal(jax.random.PRNGKey(1), (rows.shape[0], d))
+        for acc in (False, True):
+            got = ops.gossip_scatter(rows, X, U, accumulate=acc,
+                                     force="pallas")
+            want = ref.gossip_scatter_ref(rows, X, U, accumulate=acc)
+            assert got.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(got, np.float32), np.asarray(want, np.float32))
 
 
 def test_gossip_scatter_ref_rejects_block_tuning():
@@ -325,7 +332,7 @@ def test_gossip_scatter_ref_rejects_block_tuning():
 # ---------------------------------------------------------------------------
 # launch layer: the sampled step builder
 # ---------------------------------------------------------------------------
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 
 
 def _shape(name, **kw):
@@ -387,10 +394,11 @@ _SUBPROCESS_SAMPLED = textwrap.dedent("""
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import dfedpgp, sampling, topology
+    from repro.launch.mesh import make_mesh
     from repro.optim import SGD
 
     m = 8
-    mesh = jax.make_mesh((m, 1), ("data", "model"))
+    mesh = make_mesh((m, 1), ("data", "model"))
     key = jax.random.PRNGKey(0)
     cu = jax.random.normal(key, (m, 6))
     cv = jax.random.normal(jax.random.fold_in(key, 1), (m, 3))

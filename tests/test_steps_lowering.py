@@ -16,9 +16,10 @@ import pytest
 from repro.configs import SHAPES, get_reduced
 from repro.core import topology
 from repro.launch import steps
+from repro.launch.mesh import make_mesh
 
 
-MESH = jax.make_mesh((1, 1), ("data", "model"))
+MESH = make_mesh((1, 1), ("data", "model"))
 
 
 def _shape(name, **kw):
@@ -162,3 +163,25 @@ def test_fsdp_layout_lowering():
         compiled = jax.jit(fn, in_shardings=ins, out_shardings=outs).lower(
             *args).compile()
     assert compiled is not None
+
+
+def test_train_cli_layers_cut_depth_only(monkeypatch, tmp_path, capsys):
+    """train --layers N runs the model at N layers with every width kept;
+    on one device the run is unsharded and says so, and --gossip
+    ppermute is refused rather than turned into the matrix mix."""
+    from repro.launch import train
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jax.device_count() == 1
+    cfg = get_reduced("qwen2-0.5b")
+    assert cfg.n_layers > 1
+    argv = ["--arch", "qwen2-0.5b", "--reduced", "--clients", "2",
+            "--rounds", "1", "--batch", "1", "--seq", "8"]
+    state = train.main(argv + ["--layers", "1"])
+    assert capsys.readouterr().out.startswith("[train] unsharded: 2 clients")
+    assert all(x.shape[:2] == (2, 1)
+               for x in jax.tree.leaves(state.params["layers"]))
+    assert state.params["embed"].shape == (2, cfg.vocab, cfg.d_model)
+    with pytest.raises(SystemExit):
+        train.main(argv + ["--gossip", "ppermute"])
+    with pytest.raises(SystemExit):
+        train.main(argv + ["--layers", "-1"])
