@@ -1,0 +1,9 @@
+"""The benchmark's tests run on the CPU: its logic at small sizes, never a
+timing.  The program is imported from `src/` beside the benchmark."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
