@@ -24,6 +24,10 @@ from repro.obs import gauges as obs_gauges
 from repro.optim import SGD, SGDState
 from . import gossip, local, partition
 
+# the device scope of the u-step update (SGD on the shared part and the
+# step-gate blend), nested in `dfedpgp.local`
+U_UPDATE_SCOPE = "dfedpgp.u_update"
+
 
 def _check_uniform_dtype(layout) -> None:
     """The resident buffer (params AND momentum) carries one dtype while
@@ -202,14 +206,15 @@ class DFedPGP:
             g = local.masked_grads(g, mask, keep_shared=True)
             if self.grad_hook is not None:
                 g = self.grad_hook(g)
-            p2, s2 = self.opt_u.update(g, s, p, lr_scale)
-            if step_gate_u is not None:
-                gate = step_gate_u[k]
-                blend = lambda new, old: jax.tree.map(
-                    lambda a, b: (gate * a + (1.0 - gate) * b
-                                  ).astype(a.dtype), new, old)
-                p2 = blend(p2, p)
-                s2 = SGDState(blend(s2.momentum, s.momentum))
+            with jax.named_scope(U_UPDATE_SCOPE):
+                p2, s2 = self.opt_u.update(g, s, p, lr_scale)
+                if step_gate_u is not None:
+                    gate = step_gate_u[k]
+                    blend = lambda new, old: jax.tree.map(
+                        lambda a, b: (gate * a + (1.0 - gate) * b
+                                      ).astype(a.dtype), new, old)
+                    p2 = blend(p2, p)
+                    s2 = SGDState(blend(s2.momentum, s.momentum))
             # personal leaves must not move in the u-phase
             p2 = partition.where(mask, p2, p)
             return (p2, s2), loss
@@ -424,13 +429,14 @@ class DFedPGP:
             z_row = (row / mu_i).astype(row.dtype)
             loss, g = jax.value_and_grad(flat_loss)(z_row, batch)
             g = self._apply_flat_grad_hook(g)
-            row2, s2 = self.opt_u.update(g, s, row, lr_scale)
-            if step_gate_u is not None:
-                gate = step_gate_u[k]
-                blend = lambda new, old: (gate * new + (1.0 - gate) * old
-                                          ).astype(new.dtype)
-                row2 = blend(row2, row)
-                s2 = SGDState(blend(s2.momentum, s.momentum))
+            with jax.named_scope(U_UPDATE_SCOPE):
+                row2, s2 = self.opt_u.update(g, s, row, lr_scale)
+                if step_gate_u is not None:
+                    gate = step_gate_u[k]
+                    blend = lambda new, old: (gate * new + (1.0 - gate) * old
+                                              ).astype(new.dtype)
+                    row2 = blend(row2, row)
+                    s2 = SGDState(blend(s2.momentum, s.momentum))
             if self.telemetry:
                 # gauge the POST-HOOK shared gradient (what the optimizer
                 # consumed); static gate, so the off-path scan carries the
@@ -484,7 +490,8 @@ class DFedPGP:
         flat_loss = local.flat_view_loss(self.loss_fn, layout, personal)
         loss_u, g_u = jax.value_and_grad(flat_loss)(z_row, batch)
         g_u = self._apply_flat_grad_hook(g_u)
-        row2, su2 = self.opt_u.update(g_u, opt_u, flat_row, lr_scale)
+        with jax.named_scope(U_UPDATE_SCOPE):
+            row2, su2 = self.opt_u.update(g_u, opt_u, flat_row, lr_scale)
 
         if not has_v_phase:
             return row2, personal, su2, opt_v, loss_u

@@ -34,6 +34,9 @@ import numpy as np
 # (m, m) f32 table is still a "small" 64 MiB.
 MAX_DENSE_M = 4096
 
+# the host span around each schedule draw (repro.obs.profiler.span)
+TOPOLOGY_SPAN = "dfedpgp.host.topology"
+
 
 def _check_dense_degree(m: int, what: str) -> None:
     if m > MAX_DENSE_M:
@@ -462,24 +465,30 @@ class TopologySchedule:
         return jax.random.fold_in(jax.random.PRNGKey(self.seed), t)
 
     def at(self, t) -> SparseTopology:
-        """The round-t mixing pattern."""
-        if self.kind == "random":
-            return directed_random(self.key(t), self.m, self.n)
-        if self.kind == "undirected":
-            return undirected_random(self.key(t), self.m, self.n)
-        if self.kind == "exponential":
-            return directed_exponential(self.m, t)
-        if self.kind == "ring":
-            return ring(self.m)
-        return fully_connected(self.m)
+        """The round-t mixing pattern.  The draw runs eagerly on the host,
+        inside the host span `dfedpgp.host.topology`."""
+        from repro.obs.profiler import span  # obs imports this module
+        with span(TOPOLOGY_SPAN):
+            if self.kind == "random":
+                return directed_random(self.key(t), self.m, self.n)
+            if self.kind == "undirected":
+                return undirected_random(self.key(t), self.m, self.n)
+            if self.kind == "exponential":
+                return directed_exponential(self.m, t)
+            if self.kind == "ring":
+                return ring(self.m)
+            return fully_connected(self.m)
 
     __call__ = at
 
     def induced(self, t, active, renorm: str = "row") -> SparseTopology:
         """The round-t pattern restricted to the `active` subset — the ONE
         topology object stays the single source of who-talks-to-whom under
-        partial participation (docs/scale.md)."""
-        return induced_subgraph(self.at(t), active, renorm)
+        partial participation (docs/scale.md).  Also under
+        `dfedpgp.host.topology`."""
+        from repro.obs.profiler import span
+        with span(TOPOLOGY_SPAN):
+            return induced_subgraph(self.at(t), active, renorm)
 
     def permutation_offsets(self) -> tuple:
         """For one-peer schedules: the per-round pull offsets, derived from

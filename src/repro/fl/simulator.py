@@ -427,6 +427,7 @@ def run_experiment(algo_name: str, sim: SimConfig,
     timer = obs.PhaseTimer()
     t0 = time.perf_counter()
     for r in range(sim.rounds):
+        compiles0 = obs.compile_count()
         k_r = jax.random.fold_in(k_run, r)
         # 3-way split kept so the k_batch/k_cfl streams match the
         # pre-schedule RNG layout; the topology key is unused now — the
@@ -482,7 +483,7 @@ def run_experiment(algo_name: str, sim: SimConfig,
             sink.emit(obs.round_record(
                 run=run_id, algo=algo_name, step=r + 1, m=sim.m, acc=acc,
                 vtime=float((r + 1) * k_total), wire_bytes=wire_total,
-                **timer.gauges(),
+                compiles=obs.compile_count() - compiles0, **timer.gauges(),
                 **{k: v for k, v in metrics.items()
                    if jnp.ndim(v) == 0}))
             timer.reset()
@@ -594,6 +595,7 @@ def async_experiment(algo_name: str, sim: SimConfig, model_cfg, data,
     tick = 0
     wire_edges = jnp.zeros((), jnp.int32)
     for r in range(sim.rounds):
+        compiles0 = obs.compile_count()
         with timer.phase("window", block=sink is not None) as ph:
             state, metrics, tick, wire_edges = async_round(
                 runtime, tick_fn, state, schedule, data, sim, k_run, tick,
@@ -621,7 +623,7 @@ def async_experiment(algo_name: str, sim: SimConfig, model_cfg, data,
             sink.emit(obs.tick_record(
                 run=run_id, algo=algo_name, step=r + 1, m=sim.m, acc=acc,
                 wire_bytes=int(wire_edges) * wire_rb + wire_boot,
-                **timer.gauges(),
+                compiles=obs.compile_count() - compiles0, **timer.gauges(),
                 **{k: v for k, v in metrics.items()
                    if jnp.ndim(v) == 0}))
             timer.reset()

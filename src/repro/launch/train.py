@@ -231,6 +231,7 @@ def main(argv=None):
            else contextlib.nullcontext())
     with ctx, obs.maybe_trace(args.profile or None):
         for r in range(args.rounds):
+            compiles0 = obs.compile_count()
             with timer.phase("data"):
                 kr = jax.random.fold_in(key, r + 1)
                 kb, _ = jax.random.split(kr)
@@ -246,7 +247,8 @@ def main(argv=None):
                     batches = jax.device_put(batches, steps.batch_specs(
                         batches, mesh, layout, n_lead=2))
             active = None
-            with timer.phase("round", block=True) as ph:
+            # dispatch only: the fetch below is the round's one sync
+            with timer.phase("round"):
                 if sampler is not None:
                     active = jnp.asarray(sampler.active_at(r))
                     P_r = topology.induced_subgraph(schedule.at(r), active,
@@ -255,26 +257,31 @@ def main(argv=None):
                 else:
                     P_r = schedule.at(r)
                     state, metrics = round_fn(state, P_r, batches)
-                ph.out = metrics
-            metrics = jax.device_get(metrics)
-            wire_total += obs_gauges.edge_count(P_r) * wire_rb
-            rec = obs.round_record(
-                run=run_id, algo="dfedpgp", step=r, m=m,
-                loss=metrics["loss_u"], wire_bytes=wire_total,
-                round_s=timer.seconds("round"), **timer.gauges(),
-                **{k: v for k, v in metrics.items() if jnp.ndim(v) == 0})
+            with timer.phase("fetch"):
+                metrics = jax.device_get(metrics)
+            # the record cannot time its own emission: `emit` is a span
+            # on the trace and its bucket is dropped with the reset
+            with timer.phase("emit"):
+                wire_total += obs_gauges.edge_count(P_r) * wire_rb
+                rec = obs.round_record(
+                    run=run_id, algo="dfedpgp", step=r, m=m,
+                    loss=metrics["loss_u"], wire_bytes=wire_total,
+                    round_s=timer.seconds("round") + timer.seconds("fetch"),
+                    compiles=obs.compile_count() - compiles0,
+                    **timer.gauges(),
+                    **{k: v for k, v in metrics.items() if jnp.ndim(v) == 0})
+                sink.emit(rec)
+                if args.graph_every and (r + 1) % args.graph_every == 0:
+                    from repro.obs import graph as obs_graph
+                    obs_graph.emit_graph_record(
+                        sink, run_id=run_id, algo="dfedpgp", m=m,
+                        seed=args.seed, schedule=schedule, step=r, t0=r,
+                        flat=state.flat, mu=state.mu,
+                        personal=state.personal, active=active)
+                print(f"[train] {obs.record.render(rec)} "
+                      f"loss_v={rec['loss_v']:.4f} "
+                      f"mu=[{rec['mu_min']:.3f},{rec['mu_max']:.3f}]")
             timer.reset()
-            sink.emit(rec)
-            if args.graph_every and (r + 1) % args.graph_every == 0:
-                from repro.obs import graph as obs_graph
-                obs_graph.emit_graph_record(
-                    sink, run_id=run_id, algo="dfedpgp", m=m,
-                    seed=args.seed, schedule=schedule, step=r, t0=r,
-                    flat=state.flat, mu=state.mu,
-                    personal=state.personal, active=active)
-            print(f"[train] {obs.record.render(rec)} "
-                  f"loss_v={rec['loss_v']:.4f} "
-                  f"mu=[{rec['mu_min']:.3f},{rec['mu_max']:.3f}]")
     sink.close()
     if args.metrics:
         print(f"[train] metrics -> {args.metrics} "

@@ -666,3 +666,126 @@ def test_report_diff_exit_codes(tmp_path, capsys):
     assert report.main([str(a), str(b), "--diff"]) == 1
     err = capsys.readouterr().err
     assert "no step-aligned" in err
+
+
+# ---------------------------------------------------------------------------
+# spans inside the round: the u-step update's device scope, the program's
+# host spans on the profiler's clock, and the compile counter
+# ---------------------------------------------------------------------------
+def _u_update_programs():
+    loss_fn, mask, cu, cv = _quad(m=4)
+    algo = _algo(loss_fn, mask)
+    params = {"body": cu, "head": cv}
+    b = _batches(cu, cv, 2)
+    P = topology.ring(4)
+    state, layout = algo.init_flat(params)
+    prof = profiles.tiered(4, spread=3.0, push_delay_max=1, seed=1)
+    rt, s_async = AsyncRuntime.build(algo, params, prof, depth=2)
+    topo = topology.to_push_sparse(P)
+    bt = {k: v[:, 0] for k, v in b["u"].items()}
+    return {
+        "resident": jax.jit(lambda s: algo.round_fn_flat(s, P, b, layout)
+                            ).lower(state),
+        "tree": jax.jit(lambda s: algo.round_fn(s, P, b)).lower(
+            algo.init(params)),
+        "async": jax.jit(lambda s: rt.tick(s, topo, bt)).lower(s_async),
+    }
+
+
+@pytest.mark.parametrize("path", ["resident", "tree", "async"])
+def test_u_update_scope_names_the_update_in_the_compiled_round(path):
+    """The SGD update of the shared part carries `dfedpgp.u_update` in
+    every form of the step; in the resident round it nests in
+    `dfedpgp.local`, the outer scope the benchmark splits the round by.
+    (The async tick's compiled form may fuse the update under the
+    phase select that follows it, so there the lowered program is read.)"""
+    import re
+    lowered = _u_update_programs()[path]
+    if path == "async":
+        assert dfedpgp.U_UPDATE_SCOPE in lowered.as_text(debug_info=True)
+        return
+    text = lowered.compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    ours = [v for v in names if dfedpgp.U_UPDATE_SCOPE in v]
+    assert ours
+    if path == "resident":
+        assert all("/dfedpgp.local/" in v for v in ours)
+        assert not any("dfedpgp.mix" in v for v in ours)
+
+
+def _host_span_names(trace_dir):
+    from jax.profiler import ProfileData
+    path, = Path(trace_dir).rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return {ev.name for plane in pd.planes for line in plane.lines
+            for ev in line.events if ev.name.startswith("dfedpgp.host.")}
+
+
+def test_host_spans_land_in_a_trace(tmp_path):
+    sched = topology.TopologySchedule.random(8, 2, seed=3)
+    t = obs.PhaseTimer()
+    sched.at(0)                                   # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        with t.phase("round"):
+            sched.at(1)
+        with t.phase("fetch"):
+            sched.induced(2, jnp.arange(4))
+        with obs.span("dfedpgp.host.emit"):
+            pass
+    assert _host_span_names(tmp_path) == {
+        "dfedpgp.host.topology", "dfedpgp.host.round",
+        "dfedpgp.host.fetch", "dfedpgp.host.emit"}
+    assert set(t.gauges()) == {"t_round_s", "t_fetch_s"}
+
+
+def test_compile_count_counts_a_fresh_program_once():
+    x, y = jnp.arange(5.0), jnp.arange(6.0)
+    f = jax.jit(lambda x: jnp.cos(x) * 3.0 - x)
+    before = obs.compile_count()
+    f(x).block_until_ready()
+    first = obs.compile_count()
+    f(x).block_until_ready()
+    assert first - before == 1
+    assert obs.compile_count() == first
+    # another shape is another program
+    f(y).block_until_ready()
+    assert obs.compile_count() == first + 1
+
+
+def test_simulator_records_carry_compiles():
+    from repro.fl.simulator import SimConfig, run_experiment
+    from repro.spec import make_algo_spec
+
+    spec = make_algo_spec("dfedpgp", topology="random", n_neighbors=2,
+                          resident=True)
+    sim = SimConfig(m=6, rounds=3, n_train=16, n_test=8, batch=8,
+                    k_local=1, k_personal=1, spec=spec, seed=101)
+    ring = obs.RingSink(16)
+    run_experiment("dfedpgp", sim, eval_every=10, sink=ring)
+    recs = [r for r in ring.records if r["kind"] == "round"]
+    assert [r["step"] for r in recs] == [1, 2, 3]
+    assert all(isinstance(r["compiles"], int) for r in recs)
+    assert recs[0]["compiles"] >= 1               # the first round compiles
+    assert recs[1]["compiles"] == 0               # the same programs again
+    for r in recs:
+        record.validate(r)
+
+
+def test_train_records_split_round_and_fetch(tmp_path, monkeypatch):
+    """launch/train.py: the round phase is the dispatch, the fetch is the
+    round's one sync, and `round_s` is the two together."""
+    from repro.launch import train
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    path = tmp_path / "m.jsonl"
+    train.main(["--arch", "qwen2-0.5b", "--reduced", "--clients", "2",
+                "--rounds", "2", "--batch", "1", "--seq", "8",
+                "--layers", "1", "--resident", "--metrics", str(path)])
+    recs = list(record.load_jsonl(str(path)))
+    assert [r["step"] for r in recs] == [0, 1]
+    for r in recs:
+        assert {"t_data_s", "t_round_s", "t_fetch_s", "compiles"} <= set(r)
+        assert "t_emit_s" not in r
+        assert r["round_s"] == pytest.approx(r["t_round_s"] + r["t_fetch_s"],
+                                             abs=2e-6)
+    assert recs[0]["compiles"] >= 1
+    assert recs[1]["compiles"] == 0

@@ -220,3 +220,28 @@ def test_sparse_paths_unaffected_by_ceiling(monkeypatch):
     sub = topology.induced_subgraph(P, act, "row")
     assert sub.idx.shape == (3, P.k)
     np.testing.assert_allclose(np.asarray(sub.w.sum(1)), 1.0, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the draw's host span changes no table
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind,direct", [
+    ("random", lambda s, t: topology.directed_random(s.key(t), s.m, s.n)),
+    ("undirected",
+     lambda s, t: topology.undirected_random(s.key(t), s.m, s.n)),
+    ("exponential", lambda s, t: topology.directed_exponential(s.m, t)),
+    ("ring", lambda s, t: topology.ring(s.m)),
+    ("full", lambda s, t: topology.fully_connected(s.m)),
+])
+def test_at_inside_its_span_returns_the_constructors_tables(kind, direct):
+    s = topology.get_schedule(kind, 8, n=3, seed=5)
+    for t in range(3):
+        got, want = s.at(t), direct(s, t)
+        np.testing.assert_array_equal(np.asarray(got.idx),
+                                      np.asarray(want.idx))
+        np.testing.assert_array_equal(np.asarray(got.w), np.asarray(want.w))
+    active = jnp.asarray([0, 2, 5])
+    got = s.induced(1, active)
+    want = topology.induced_subgraph(direct(s, 1), active, "row")
+    np.testing.assert_array_equal(np.asarray(got.idx), np.asarray(want.idx))
+    np.testing.assert_array_equal(np.asarray(got.w), np.asarray(want.w))
