@@ -28,6 +28,7 @@ module provides the O(m*k*d) path (docs/gossip.md):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -170,12 +171,10 @@ class FlatLayout:
         """One client's (d_flat,) view -> shared subtree (unstacked leaves,
         cast to each leaf's dtype).  Under jit the slices/reshapes are
         views — this is the only point where the tree form materializes,
-        at the loss_fn leaf boundary."""
-        out, off = [], 0
-        for shape, dt, n in zip(self.shapes, self.dtypes, self.sizes):
-            out.append(row[off:off + n].reshape(shape).astype(dt))
-            off += n
-        return jax.tree.unflatten(self.treedef, out)
+        at the loss_fn leaf boundary.  Its gradient is the leaf gradients
+        concatenated into one row (`_unravel_row`)."""
+        leaves = _unravel_row(self, jnp.dtype(row.dtype), row)
+        return jax.tree.unflatten(self.treedef, list(leaves))
 
     def unravel(self, flat: jnp.ndarray):
         """(m, d_flat) buffer -> stacked shared subtree."""
@@ -186,6 +185,40 @@ class FlatLayout:
                        .astype(dt))
             off += n
         return jax.tree.unflatten(self.treedef, out)
+
+
+def _slice_row(layout: FlatLayout, row: jnp.ndarray) -> tuple:
+    out, off = [], 0
+    for shape, dt, n in zip(layout.shapes, layout.dtypes, layout.sizes):
+        out.append(row[off:off + n].reshape(shape).astype(dt))
+        off += n
+    return tuple(out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _unravel_row(layout: FlatLayout, row_dtype, row: jnp.ndarray) -> tuple:
+    """The row's leaf views, differentiated by hand: autodiff transposes
+    each slice into a pad of the full row width and sums the pads, so every
+    element of the row gradient passes one bounds test per leaf (a
+    VPU-bound pass at LM width).  The backward writes each leaf's
+    cotangent into its own range once, with one concatenate; the values
+    are the same, each element being one leaf's cotangent plus exact
+    zeros."""
+    return _slice_row(layout, row)
+
+
+def _unravel_row_fwd(layout, row_dtype, row):
+    return _slice_row(layout, row), None
+
+
+def _unravel_row_bwd(layout, row_dtype, _, cts):
+    pieces = [g.reshape(-1).astype(row_dtype) for g in cts]
+    if not pieces:      # an all-personal mask: no shared leaf, d_flat == 0
+        return (jnp.zeros((0,), row_dtype),)
+    return (jnp.concatenate(pieces),)
+
+
+_unravel_row.defvjp(_unravel_row_fwd, _unravel_row_bwd)
 
 
 class FlatClientState(NamedTuple):

@@ -36,8 +36,9 @@ def flat_view_loss(loss_fn: Callable, layout, personal_i):
     The resident-buffer path (core/dfedpgp.py round_fn_flat) keeps the
     shared part in the (m, d_flat) buffer across rounds; local SGD differs
     through this wrapper, which unravels the row into leaf views ONLY at
-    the loss_fn boundary — under jit the slices/reshapes are views, so the
-    gradient comes back as one flat row with no per-leaf concat."""
+    the loss_fn boundary — under jit the slices/reshapes are views, and
+    the gradient comes back as one flat row, the leaf gradients written
+    into it by one concatenate (FlatLayout.unravel_row's backward)."""
     def wrapped(flat_row, batch):
         shared = layout.unravel_row(flat_row)
         return loss_fn(partition.merge(shared, personal_i), batch)

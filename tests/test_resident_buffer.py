@@ -51,6 +51,78 @@ def test_flat_client_state_to_tree():
                                       np.asarray(params[k]))
 
 
+_ODD_SHAPES = [(), (3,), (5, 7), (1,), (2, 3, 5), (11,), (13, 1), (4, 9),
+               (7,)]
+
+
+def _odd_layout(m):
+    """Nine shared leaves of odd sizes (a 0-d one, a bfloat16 one) around a
+    personal leaf."""
+    ks = jax.random.split(jax.random.PRNGKey(5), len(_ODD_SHAPES) + 1)
+    params = {f"s{i}": jax.random.normal(k, (m,) + s)
+              for i, (k, s) in enumerate(zip(ks, _ODD_SHAPES))}
+    params["s4"] = params["s4"].astype(jnp.bfloat16)
+    params["head"] = jax.random.normal(ks[-1], (m, 2))
+    mask = {k: k != "head" for k in params}
+    return params, mask, gossip.FlatLayout.build(params, mask)
+
+
+def _leaf_loss(shared):
+    return sum(jnp.sum(jnp.sin(x.astype(jnp.float32)) * (i + 1.5))
+               for i, x in enumerate(jax.tree.leaves(shared)))
+
+
+def _concat_leaf_grads(layout, rows):
+    """The reference row gradient: the tree-form gradient of each leaf at
+    the row's views, concatenated."""
+    g = jax.vmap(jax.grad(_leaf_loss))(layout.unravel(rows))
+    return jnp.concatenate([x.reshape(rows.shape[0], -1).astype(rows.dtype)
+                            for x in jax.tree.leaves(g)], axis=1)
+
+
+def test_unravel_row_forward_is_the_slices():
+    params, mask, layout = _odd_layout(3)
+    flat = layout.pack(params, mask)
+    assert len(layout.sizes) == 9 and layout.d_flat == flat.shape[1]
+    fwd_row = jax.jit(jax.vmap(layout.unravel_row))(flat)
+    fwd = jax.jit(layout.unravel)(flat)
+    off = 0
+    for k, shape, n in zip(sorted(k for k in params if k != "head"),
+                           layout.shapes, layout.sizes):
+        want = flat[:, off:off + n].reshape((3,) + shape).astype(
+            params[k].dtype)
+        off += n
+        assert fwd_row[k].dtype == fwd[k].dtype == params[k].dtype
+        np.testing.assert_array_equal(np.asarray(fwd_row[k]),
+                                      np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(fwd[k]), np.asarray(want))
+
+
+def test_unravel_row_grad_is_concat_of_leaf_grads():
+    """The row gradient under vmap over clients, and along a scan of SGD
+    steps, equals the per-leaf gradients concatenated, bit for bit."""
+    params, mask, layout = _odd_layout(4)
+    flat = layout.pack(params, mask)
+    row_grad = jax.vmap(jax.grad(lambda r: _leaf_loss(layout.unravel_row(r))))
+
+    got = jax.jit(row_grad)(flat)
+    want = jax.jit(lambda f: _concat_leaf_grads(layout, f))(flat)
+    assert got.shape == flat.shape and got.dtype == flat.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def steps(grad_fn):
+        def step(rows, _):
+            g = grad_fn(rows)
+            return rows - 0.1 * g, g
+        return jax.jit(lambda f: jax.lax.scan(step, f, None, length=3))
+
+    rows_got, gs_got = steps(row_grad)(flat)
+    rows_want, gs_want = steps(lambda f: _concat_leaf_grads(layout, f))(flat)
+    np.testing.assert_array_equal(np.asarray(gs_got), np.asarray(gs_want))
+    np.testing.assert_array_equal(np.asarray(rows_got),
+                                  np.asarray(rows_want))
+
+
 @pytest.mark.parametrize("mode", ["dense", "sparse", "pallas"])
 def test_mix_flat_matches_tree_gossip(mode):
     params, mask = _tree(jax.random.PRNGKey(2), 9)
